@@ -37,8 +37,7 @@ def run(verbose=True):
         for name, fn in units.items():
             jfn = jax.jit(fn)
             lowered = jfn.lower(x)
-            from repro.compat import cost_analysis
-            ca = cost_analysis(lowered.compile())
+            ca = lowered.compile().cost_analysis()
             us = _timed(jfn, x)
             rows.append(dict(k=k, unit=name, us=us,
                              flops=ca.get("flops", 0.0),

@@ -58,8 +58,7 @@ def run(verbose=True):
                 out = f(h, w)
             out.block_until_ready()
             us = (time.perf_counter() - t0) / 10 * 1e6
-            from repro.compat import cost_analysis
-            ca = cost_analysis(f.lower(h, w).compile())
+            ca = f.lower(h, w).compile().cost_analysis()
             rows.append(dict(B=B, D=D, V=V, name=name, us=us,
                              flops=ca.get("flops"),
                              bytes=ca.get("bytes accessed")))

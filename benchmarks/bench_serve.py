@@ -49,6 +49,7 @@ import jax
 import numpy as np
 
 from repro.configs import ARCHS, smoke_config
+from repro.launch import compile_cache
 from repro.models import lm
 from repro.serve.engine import Request, ServeEngine
 
@@ -1048,6 +1049,7 @@ def main():
                     help="router replica counts crossed with --tps")
     ap.add_argument("--out", default="BENCH_serve.json")
     args = ap.parse_args()
+    compile_cache.enable()
     rows = run(arch=args.arch, slot_counts=tuple(args.slots),
                n_requests=args.requests, max_new=args.max_new,
                max_len=args.max_len)

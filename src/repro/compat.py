@@ -1,68 +1,24 @@
-"""Version compatibility for the jax API surface this repo uses.
+"""The two jax entry points this repo calls with fixed options.
 
-The repo targets the installed jax (0.4.x at the time of writing) but is
-written against the newer spellings where possible. Everything that moved
-between 0.4.x and 0.5+/0.6+ funnels through here:
-
-  - ``shard_map``: ``jax.shard_map(..., check_vma=...)`` on new jax,
-    ``jax.experimental.shard_map.shard_map(..., check_rep=...)`` on 0.4.x.
-  - ``make_mesh``: the ``axis_types`` kwarg (and ``jax.sharding.AxisType``)
-    only exist on newer jax; on 0.4.x a plain ``Mesh`` is equivalent for
-    everything this repo does (no explicit-sharding mode).
+  - ``make_mesh``: ``jax.make_mesh`` with every axis ``Auto`` (the repo
+    relies on GSPMD propagation, never on explicit-sharding mode).
+  - ``shard_map``: ``jax.shard_map`` with ``check_vma=False`` (the repo
+    never relies on varying-manual-axes checks).
 """
 from __future__ import annotations
 
-import inspect
-
 import jax
-
-_MAKE_MESH_HAS_AXIS_TYPES = (
-    "axis_types" in inspect.signature(jax.make_mesh).parameters
-)
 
 
 def make_mesh(axis_shapes, axis_names, *, devices=None):
-    """``jax.make_mesh`` with Auto axis types where the kwarg exists."""
-    axis_shapes = tuple(axis_shapes)
+    """``jax.make_mesh`` with Auto axis types."""
     axis_names = tuple(axis_names)
-    if _MAKE_MESH_HAS_AXIS_TYPES:
-        auto = (jax.sharding.AxisType.Auto,) * len(axis_names)
-        return jax.make_mesh(axis_shapes, axis_names, devices=devices,
-                             axis_types=auto)
-    return jax.make_mesh(axis_shapes, axis_names, devices=devices)
+    auto = (jax.sharding.AxisType.Auto,) * len(axis_names)
+    return jax.make_mesh(tuple(axis_shapes), axis_names, devices=devices,
+                         axis_types=auto)
 
 
-def cost_analysis(compiled) -> dict:
-    """``compiled.cost_analysis()`` as a flat dict (0.4.x returns a
-    one-entry list of per-program dicts; newer jax returns the dict)."""
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return ca or {}
-
-
-def abstract_mesh(axis_shapes, axis_names):
-    """``jax.sharding.AbstractMesh`` across the 0.4.x -> 0.5+ signature
-    change (0.4.x takes ``((name, size), ...)`` pairs; newer jax takes
-    ``(sizes, names)``)."""
-    AbstractMesh = jax.sharding.AbstractMesh
-    try:
-        return AbstractMesh(tuple(axis_shapes), tuple(axis_names))
-    except TypeError:
-        return AbstractMesh(tuple(zip(axis_names, axis_shapes)))
-
-
-if hasattr(jax, "shard_map"):
-
-    def shard_map(f, *, mesh, in_specs, out_specs):
-        """Unchecked shard_map (the repo never relies on rep/vma checks)."""
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-
-else:
-    from jax.experimental.shard_map import shard_map as _shard_map_04
-
-    def shard_map(f, *, mesh, in_specs, out_specs):
-        """Unchecked shard_map (the repo never relies on rep/vma checks)."""
-        return _shard_map_04(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_rep=False)
+def shard_map(f, *, mesh, in_specs, out_specs):
+    """Unchecked shard_map (the repo never relies on rep/vma checks)."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)

@@ -32,6 +32,13 @@ mixed widths share one compiled call.
   k/v   (1, bs, Hkv, hd) indexed (btab[b, j], 0, 0, 0)   <- the trick
   out   (1, T, Hq, hd)  written at j == nb - 1
 
+The whole query window stays resident in VMEM: the q and out blocks,
+the ``(T * Hq, hd)`` f32 accumulator and its max/sum columns.  At
+qwen3-0.6b widths (Hq 16, hd 128, 16-row blocks) that compiles for a
+TPU v5e up to T = 128; T = 256 asks for 18.2 MB of scoped VMEM, over the
+16 MB limit, so chunk and draft windows stay at or below 128 until the
+kernel tiles T.
+
 Inner loop is the standard online-softmax carry (same (m, l, acc)
 recurrence as kernels/flash_attention.py) over ``T * Hq`` query rows,
 GQA-native: scores are computed per KV head over its ``g = Hq // Hkv``
@@ -52,7 +59,9 @@ comparator carry: the output is the V row of the running max score).
 on top of the causal cap, so ``maxonly`` + ``window`` is the paper's
 comparator over a sliding bus.  Both knobs branch at TRACE time —
 ``attn_approx='exact'``/``window=None`` traces the exact same graph as
-before they existed.
+before they existed.  ``base2`` and ``pwl`` look their weights up in a
+table, a gather Mosaic does not lower, so they run in interpret mode
+only; the compiled kernel refuses them.
 
 Validated in interpret mode against ``ref.paged_attention`` (which is
 itself the dense decode math applied to the gathered view).
@@ -71,6 +80,9 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.core import attn_approx as approx
 
 _NEG_INF = float("-inf")
+# score functions whose weight is a table lookup (base-2 fractional LUT,
+# PWL endpoint ROM): interpret mode only
+_LUT_MODES = ("base2", "pwl")
 
 
 def _kernel(btab_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
@@ -104,8 +116,13 @@ def _kernel(btab_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
     # each query's own position: kv entries past it (later drafts, other
     # rows' longer extents, padded table columns) are masked out
     # entirely, so ragged batches and draft windows share one grid.
-    pos_row = jnp.stack([pos_ref[bi, t] for t in range(tq)])      # (T,)
-    thr = jnp.repeat(pos_row, hq)[:, None]                 # (T*Hq, 1)
+    # Score row r belongs to query token r // Hq.  The threshold column
+    # is built from a 2-D row iota and one select per token: Mosaic
+    # refuses the (T, Hq) -> (T*Hq, 1) shape cast a stack + repeat needs.
+    row = jax.lax.broadcasted_iota(jnp.int32, (tq * hq, 1), 0)
+    thr = jnp.full((tq * hq, 1), pos_ref[bi, 0], jnp.int32)
+    for t in range(1, tq):
+        thr = jnp.where(row >= t * hq, pos_ref[bi, t], thr)  # (T*Hq, 1)
     kv_pos = j * block_size + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
     valid = kv_pos <= thr
     if window is not None:
@@ -181,6 +198,12 @@ def paged_attention(q, k_pool, v_pool, block_tables, positions, *,
     ``core.attn_approx`` catalog; ``window`` caps each query to its last
     ``window`` kv positions.  Both are static (per-mode compilation).
     -> (B, Hq, hd) / (B, T, Hq, hd)."""
+    if not interpret and attn_approx in _LUT_MODES:
+        raise NotImplementedError(
+            f"attn_approx={attn_approx!r} has no compiled TPU kernel: its "
+            "table lookup is a gather Mosaic cannot lower (it lowers only "
+            "2-D gathers).  Run it with use_pallas=False (the jnp "
+            "reference) or interpret=True.")
     multi = q.ndim == 4
     if not multi:
         q = q[:, None]

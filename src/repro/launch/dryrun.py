@@ -84,8 +84,7 @@ def run_cell(arch: str, shape_name: str, mesh_name: str, *,
             compiled = lowered.compile()
         mem = hlo_stats.memory_report(compiled)
         coll = hlo_stats.collective_bytes(compiled.as_text())
-        from repro import compat
-        ca = compat.cost_analysis(compiled)
+        ca = compiled.cost_analysis()
         # args/out/alias are PER-DEVICE; temp is PROGRAM-WIDE on the
         # host-simulated backend (all partitions share one arena) -> /chips.
         hbm = None

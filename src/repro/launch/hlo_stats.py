@@ -142,9 +142,7 @@ ZERO = RooflineTerms(0.0, 0.0, 0.0, {})
 
 
 def cost_terms(compiled) -> RooflineTerms:
-    from repro import compat
-
-    ca = compat.cost_analysis(compiled)
+    ca = compiled.cost_analysis()
     txt = compiled.as_text()
     cb = collective_bytes(txt)
     return RooflineTerms(

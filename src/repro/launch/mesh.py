@@ -2,8 +2,7 @@
 
 Defined as FUNCTIONS so importing this module never touches jax device
 state (the dry-run must set XLA_FLAGS before any jax initialization).
-Mesh construction goes through ``repro.compat`` so the same code runs on
-jax versions with and without ``axis_types`` / ``AxisType``.
+Mesh construction goes through ``repro.compat`` (every axis ``Auto``).
 """
 from __future__ import annotations
 

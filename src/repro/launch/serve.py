@@ -32,7 +32,7 @@ import jax
 import numpy as np
 
 from repro.configs import get_config, smoke_config
-from repro.launch import mesh as mesh_mod
+from repro.launch import compile_cache, mesh as mesh_mod
 from repro.models import lm
 from repro.serve import sampler as sampler_mod
 from repro.serve.engine import Request, ServeEngine
@@ -179,6 +179,7 @@ def main():
                          "on this port and serve until interrupted")
     ap.add_argument("--http-host", default="127.0.0.1")
     args = ap.parse_args()
+    compile_cache.enable()
 
     cfg = get_config(args.arch)
     if args.smoke:

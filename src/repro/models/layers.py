@@ -395,9 +395,10 @@ def attention(
             and not cfg.seq_parallel_attn):
         # Pallas flash attention (prefill / fwd-only): scores never reach
         # HBM; GQA-native (no KV repeat); causal + sliding window.
+        from repro.kernels import ops as kernel_ops
         from repro.kernels.flash_attention import flash_attention
 
-        interp = jax.default_backend() != "tpu"
+        _, interp = kernel_ops.resolve_flags(True, None)
         o = flash_attention(
             q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
             v.transpose(0, 2, 1, 3), causal=causal, window=window,

@@ -3,7 +3,7 @@
 The facade over the continuous-batching engine, for callers who want an
 inference API rather than an engine loop:
 
-  llm = LLM.from_arch("qwen3-0.6b", smoke=True)
+  llm = LLM.from_arch("qwen3-0.6b")           # published widths
   outs = llm.generate(prompts, SamplingParams(max_new_tokens=16))
   for chunk in llm.stream(prompt, SamplingParams(stop=[(7, 9)])):
       ...                     # TokenChunk per token, incrementally
@@ -77,10 +77,11 @@ class LLM:
         self.engine.add_consumer(self._on_chunk)
 
     @classmethod
-    def from_arch(cls, arch: str, *, smoke: bool = True, seed: int = 0,
+    def from_arch(cls, arch: str, *, smoke: bool = False, seed: int = 0,
                   **engine_kwargs) -> "LLM":
-        """Build params + config for a zoo arch and wrap them.  Always
-        pass ``smoke=True`` off-accelerator — full configs are huge."""
+        """Build random params (from ``seed``) + config for a zoo arch at
+        its published widths and wrap them.  ``smoke=True`` shrinks the
+        widths to ``smoke_config`` for CPU runs."""
         import jax
 
         from repro.configs import get_config, smoke_config
@@ -130,11 +131,16 @@ class LLM:
         self._pump_thread.start()
 
     def stop_pump(self) -> None:
+        """Stop the background pump; re-raise the error it died of, if
+        any, so a server shutting down never swallows an engine fault."""
         if self._pump_thread is None:
             return
         self._pump_stop.set()
         self._pump_thread.join()
         self._pump_thread = None
+        if self._pump_error is not None:
+            raise RuntimeError(
+                "engine pump thread died") from self._pump_error
 
     # -- submission ----------------------------------------------------------
     def submit(self, prompt: PromptLike,
@@ -309,4 +315,9 @@ class LLM:
         return {"engine": snap, "kv": usage,
                 "replicas": [{"replica": 0, "engine": snap, "kv": usage,
                               "healthy": self._pump_error is None,
-                              "draining": False}]}
+                              "draining": False,
+                              "devices": self.device_names()}]}
+
+    def device_names(self) -> List[str]:
+        """``platform:id`` of every device this engine's params live on."""
+        return [f"{d.platform}:{d.id}" for d in self.engine.devices]

@@ -337,7 +337,7 @@ class ServeEngine:
                  prefix_cache: bool = True,
                  attn_approx: Optional[str] = None,
                  attn_window: Optional[int] = None,
-                 tp: Optional[int] = None):
+                 tp: Optional[int] = None, device=None):
         # Approximate attention: the kwargs are a convenience over the
         # ModelConfig fields (sentinel None = keep whatever the caller's
         # cfg says, so a cfg already carrying a mode isn't clobbered).
@@ -389,6 +389,15 @@ class ServeEngine:
                 shard_rules.named(
                     shard_rules.serve_param_specs(params, mesh, cfg),
                     mesh))
+        # ``device`` pins a mesh-less engine (params and KV pools) to one
+        # device — how a Router puts its tp=1 replicas on distinct chips.
+        # Uncommitted step inputs (token ids, tables) follow the
+        # committed params there.
+        if device is not None:
+            if mesh is not None:
+                raise ValueError("pass ONE of device= (a one-device "
+                                 "engine) or tp=/mesh= (a sharded one)")
+            params = jax.device_put(params, device)
         self.tp = int(tp) if tp is not None else (
             int(mesh.shape.get("model", 1)) if mesh is not None else 1)
         self.params = params
@@ -431,9 +440,17 @@ class ServeEngine:
         # width-1 step.
         self.spec_capable = (cfg.attention_window is None and all(
             k == "attn" for k in lm.layer_types(cfg)))
-        self.store = PagedKVStore(
-            params, cfg, n_slots=n_slots, max_len=max_len,
-            block_size=block_size, num_blocks=num_blocks, layout=kv_layout)
+        with jax.default_device(device):
+            self.store = PagedKVStore(
+                params, cfg, n_slots=n_slots, max_len=max_len,
+                block_size=block_size, num_blocks=num_blocks,
+                layout=kv_layout)
+        # where this engine's params live (and so where its steps run):
+        # the mesh's devices, the pinned device, or JAX's default one
+        self.devices = sorted(
+            set().union(*(a.devices() for a in jax.tree.leaves(params)
+                          if isinstance(a, jax.Array))),
+            key=lambda d: d.id)
         if self.tp > 1 and self.store.any_paged:
             # paged pools sharded HEAD-WISE over 'model': each device
             # scatters / attends only its own kv-head slice (head counts

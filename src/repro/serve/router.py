@@ -26,12 +26,13 @@ its in-flight requests run to completion — the rolling-restart
 primitive.  Draining/unhealthy replicas are skipped by the router; if
 every replica is unhealthy, submission raises.
 
-Tensor parallelism composes per replica: ``Router(..., tp=T)`` gives
-each replica its own DISJOINT ``T``-device slice of the host platform
-when ``replicas * T`` devices exist (replica r gets devices
-``[r*T, (r+1)*T)``), and falls back to sharing devices ``[0, T)``
-otherwise — correct either way, the slices only matter for real
-parallel speedup.
+Placement: replica r gets device slice r — devices ``[r*T, (r+1)*T)``
+for ``tp=T`` (a ``(1, T)`` mesh), device ``r`` for a one-device replica
+— taken modulo the devices present.  Slices are disjoint while
+``replicas * T`` devices exist and wrap around onto shared devices
+beyond that; correct either way, the slices only matter for real
+parallel speedup.  Each replica's ``/v1/stats`` entry names its
+devices.
 
 Stats aggregate across replicas with explicit merge rules (the shape
 GET /v1/stats serves — see ``aggregate_engine_stats``): counters SUM,
@@ -170,40 +171,38 @@ class Router:
                  tp: Optional[int] = None, **engine_kwargs):
         if replicas < 1:
             raise ValueError(f"replicas={replicas}: must be >= 1")
-        meshes: List[Optional[object]] = [None] * replicas
-        if tp is not None and tp > 1:
-            import jax
+        import jax
 
-            from repro import compat
-            devs = jax.devices()
-            if len(devs) < tp:
-                raise ValueError(
-                    f"tp={tp} needs {tp} devices; only {len(devs)} "
-                    "visible (on a CPU host set XLA_FLAGS="
-                    f"--xla_force_host_platform_device_count="
-                    f"{replicas * tp} before jax initializes)")
-            for r in range(replicas):
-                # disjoint per-replica device slices when they exist;
-                # otherwise every replica shares devices [0, tp).
-                lo = r * tp
-                sl = (devs[lo:lo + tp] if lo + tp <= len(devs)
-                      else devs[:tp])
-                meshes[r] = compat.make_mesh((1, tp), ("data", "model"),
-                                             devices=sl)
+        from repro import compat
+        devs = jax.devices()
+        width = tp if tp is not None and tp > 1 else 1
+        if len(devs) < width:
+            raise ValueError(
+                f"tp={tp} needs {tp} devices; only {len(devs)} "
+                "visible (on a CPU host set XLA_FLAGS="
+                f"--xla_force_host_platform_device_count="
+                f"{replicas * tp} before jax initializes)")
         self.replicas = []
         for r in range(replicas):
+            # replica r owns device slice r, [r*width, (r+1)*width),
+            # taken modulo the devices present: disjoint slices while
+            # replicas * width devices exist, shared ones beyond that.
+            sl = [devs[(r * width + j) % len(devs)] for j in range(width)]
             kw = dict(engine_kwargs)
             if tp is not None:
                 kw["tp"] = tp
-            if meshes[r] is not None:
-                kw["mesh"] = meshes[r]
+            if width > 1:
+                kw["mesh"] = compat.make_mesh((1, width), ("data", "model"),
+                                              devices=sl)
+            else:
+                kw["device"] = sl[0]
             self.replicas.append(Replica(r, LLM(params, cfg, **kw)))
         self.cfg = self.replicas[0].llm.cfg
         self._route_lock = threading.Lock()
         self._sessions: dict = {}          # session id -> replica idx
 
     @classmethod
-    def from_arch(cls, arch: str, *, smoke: bool = True, seed: int = 0,
+    def from_arch(cls, arch: str, *, smoke: bool = False, seed: int = 0,
                   **kwargs) -> "Router":
         import jax
 
@@ -317,8 +316,17 @@ class Router:
             r.llm.start_pump(idle_wait)
 
     def stop_pump(self) -> None:
+        """Stop every replica's pump, then re-raise the first pump error
+        (``LLM.stop_pump``) — one dead replica never leaves the others
+        running."""
+        first = None
         for r in self.replicas:
-            r.llm.stop_pump()
+            try:
+                r.llm.stop_pump()
+            except RuntimeError as e:
+                first = first or e
+        if first is not None:
+            raise first
 
     def drain(self, idx: int, wait: bool = False,
               timeout: float = 60.0) -> None:
@@ -373,7 +381,8 @@ class Router:
             pools.append(pool)
             reps.append({"replica": r.idx, "engine": snap, "kv": usage,
                          "healthy": r.healthy, "draining": r.draining,
-                         "routed": r.served})
+                         "routed": r.served,
+                         "devices": r.llm.device_names()})
         return {"engine": aggregate_engine_stats(snaps, pools),
                 "kv": aggregate_kv(usages),
                 "replicas": reps}

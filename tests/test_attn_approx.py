@@ -192,6 +192,19 @@ def test_paged_kernel_multi_token_variant(variant):
                                    atol=TOL[variant])
 
 
+@pytest.mark.parametrize("variant", ["base2", "pwl"])
+def test_compiled_kernel_refuses_lut_variants(variant):
+    """base2/pwl look their weights up in a table, a gather Mosaic does
+    not lower: the compiled kernel raises its own clear error before
+    Mosaic's, and never substitutes the reference."""
+    rng = np.random.default_rng(0)
+    q, kp, vp, bt = _pool_case(rng, pos=20, bs=8, g=2)
+    pos = jnp.full((q.shape[0],), 20, jnp.int32)
+    with pytest.raises(NotImplementedError, match=variant):
+        ops.paged_attention(q, kp, vp, bt, pos, use_pallas=True,
+                            interpret=False, attn_approx=variant)
+
+
 def test_windowed_paged_matches_dense_slice_oracle():
     """paged(window=w) == plain softmax attention over the dense slice
     [pos-w+1, pos] — an oracle built independently of both twins."""

@@ -29,10 +29,10 @@ REPO = Path(__file__).resolve().parent.parent
 # Metadata: every param/batch/cache spec divides the production meshes
 # ---------------------------------------------------------------------------
 def _abstract_mesh(multi_pod):
-    from repro.compat import abstract_mesh
+    from jax.sharding import AbstractMesh
     if multi_pod:
-        return abstract_mesh((2, 16, 16), ("pod", "data", "model"))
-    return abstract_mesh((16, 16), ("data", "model"))
+        return AbstractMesh((2, 16, 16), ("pod", "data", "model"))
+    return AbstractMesh((16, 16), ("data", "model"))
 
 
 def _check_divisible(tree, specs, mesh, where):

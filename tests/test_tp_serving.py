@@ -15,6 +15,8 @@ TP TRUNK (subprocess, 8 forced host devices — real pjit execution):
     collective result shapes.
   - ``Router(replicas=2, tp=2)`` == single unsharded ``LLM`` on the
     same trace (sharding x replication composes).
+  - (4 forced host devices) ``Router(replicas=4)`` puts each tp=1
+    replica's params and KV pools on its own device.
 
 ROUTER (host-side, any device count — routing logic needs no mesh):
   - routing order: session affinity > prefix affinity > least-loaded
@@ -46,21 +48,20 @@ from repro.serve.router import (Router, aggregate_engine_stats,
 REPO = Path(__file__).resolve().parent.parent
 
 
-def _run_sub(body: str) -> str:
-    """Run ``body`` in a fresh interpreter with 8 forced host devices
-    (the flag must be set before jax initializes, hence the subprocess
-    — same pattern as test_distributed)."""
-    script = textwrap.dedent("""
+def _run_sub(body: str, devices: int = 8) -> str:
+    """Run ``body`` in a fresh interpreter with ``devices`` forced host
+    devices (the flag must be set before jax initializes, hence the
+    subprocess — same pattern as test_distributed)."""
+    flag = f"--xla_force_host_platform_device_count={devices}"
+    script = textwrap.dedent(f"""
         import os
-        os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+        os.environ["XLA_FLAGS"] = "{flag}"
         import jax, jax.numpy as jnp, numpy as np
         from repro.configs import ARCHS, smoke_config
         from repro.launch import mesh as mesh_mod, hlo_stats
         from repro.parallel import env
     """) + textwrap.dedent(body)
-    env_ = dict(os.environ,
-                PYTHONPATH=str(REPO / "src"),
-                XLA_FLAGS="--xla_force_host_platform_device_count=8")
+    env_ = dict(os.environ, PYTHONPATH=str(REPO / "src"), XLA_FLAGS=flag)
     out = subprocess.run([sys.executable, "-c", script], env=env_,
                          capture_output=True, text=True, timeout=900)
     assert out.returncode == 0, out.stderr[-3000:]
@@ -401,6 +402,46 @@ def test_tp_router_matches_unsharded_llm_8dev():
         print("ROUTER TP OK")
     """)
     assert "ROUTER TP OK" in out
+
+
+def test_router_places_replicas_on_distinct_devices_4dev():
+    """Replica r owns device r (modulo the devices present): four tp=1
+    replicas put their params AND KV pools on four distinct devices,
+    /v1/stats names them, a fifth wraps onto device 0, and the fleet
+    still emits the single LLM's tokens."""
+    out = _run_sub("""
+        from repro.models import lm
+        from repro.serve.api import LLM
+        from repro.serve.params import SamplingParams
+        from repro.serve.router import Router
+        cfg = smoke_config(ARCHS["qwen3-0.6b"])
+        params = lm.init_params(cfg, jax.random.PRNGKey(0))
+        rng = np.random.default_rng(3)
+        prompts = [rng.integers(0, cfg.vocab_size, 5 + 3 * i)
+                     .astype(np.int32) for i in range(4)]
+        sp = SamplingParams(max_new_tokens=5)
+        single = LLM(params, cfg, n_slots=2, max_len=32, eos_id=-1)
+        want = [list(o.token_ids) for o in single.generate(prompts, sp)]
+
+        router = Router(params, cfg, replicas=4, n_slots=2, max_len=32,
+                        eos_id=-1)
+        devs = jax.devices()
+        for r in router.replicas:
+            eng = r.llm.engine
+            assert eng.devices == [devs[r.idx]], (r.idx, eng.devices)
+            for pool in eng.store.pools:
+                assert pool.devices() == {devs[r.idx]}, r.idx
+        names = [rep["devices"] for rep in router.stats_payload()["replicas"]]
+        assert names == [[f"cpu:{d.id}"] for d in devs], names
+        got = [list(o.token_ids) for o in router.generate(prompts, sp)]
+        assert got == want, (got, want)
+        assert all(r.served == 1 for r in router.replicas)
+
+        wrap = Router(params, cfg, replicas=5, n_slots=2, max_len=32)
+        assert wrap.replicas[4].llm.engine.devices == [devs[0]]
+        print("ROUTER PLACEMENT OK")
+    """, devices=4)
+    assert "ROUTER PLACEMENT OK" in out
 
 
 def test_sharded_head_collectives_are_o_k_not_o_v_8dev():
