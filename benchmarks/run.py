@@ -5,7 +5,6 @@ Prints ``name,us_per_call,derived`` CSV lines per the harness contract.
   fig23               Figs 2/3 monotonicity
   bench_head_units    unit cost vs class count k (the paper's size claim)
   bench_kernels       fused reduced head vs unfused pipeline
-  roofline            summary of the dry-run roofline artifacts (if present)
 
 ``bench_serve`` (engine tokens/sec, reduced vs softmax head over the
 paged-KV engine) is intentionally not in the default sweep — it takes a
@@ -33,22 +32,6 @@ def main() -> None:
             failures.append(name)
             traceback.print_exc()
             print(f"{name},0,FAILED={e!r}")
-    # roofline summary (optional: requires dry-run artifacts)
-    try:
-        from benchmarks import roofline
-        rows = roofline.load()
-        if rows:
-            print("# --- roofline (from artifacts/dryrun) ---")
-            for r in rows:
-                if "totals" not in r:
-                    continue
-                t = r["totals"]
-                tb = max(t["t_compute_s"], t["t_memory_s"],
-                         t["t_collective_s"])
-                print(f"roofline_{r['arch']}_{r['shape']}_{r['mesh']},0,"
-                      f"bottleneck={t['bottleneck']}_tbound={tb:.3e}s")
-    except Exception:
-        traceback.print_exc()
     if failures:
         sys.exit(f"benchmark sections failed: {failures}")
 

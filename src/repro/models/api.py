@@ -172,9 +172,10 @@ def serve_decode_multi(params, cfg: ModelConfig, token: jax.Array, cache,
         split = jax.vmap(jax.random.split)(ks)
         next_keys, use_keys = split[:, 0], split[:, 1]
         sampled = tok
-        for s, rows in zip(samplers, row_sets):
-            ids = s.sample_device(params, cfg, h[rows], use_keys[rows])
-            sampled = sampled.at[rows].set(ids.astype(i32))
+        with jax.named_scope("head"):
+            for s, rows in zip(samplers, row_sets):
+                ids = s.sample_device(params, cfg, h[rows], use_keys[rows])
+                sampled = sampled.at[rows].set(ids.astype(i32))
         new_tok = jnp.where(active, sampled, tok)
         new_p = jnp.where(active, p + 1, p)
         out = out.at[:, step].set(jnp.where(active, sampled, out[:, step]))

@@ -98,11 +98,12 @@ def is_glu(kind: str) -> bool:
 # Dense MLP
 # ---------------------------------------------------------------------------
 def mlp(p, x: jax.Array, kind: str) -> jax.Array:
-    if is_glu(kind):
-        h = activate(x @ p["w_gate"], x @ p["w_up"], kind)
-    else:
-        h = activate(x @ p["w_in"], None, kind)
-    return h @ p["w_out"]
+    with jax.named_scope("mlp"):
+        if is_glu(kind):
+            h = activate(x @ p["w_gate"], x @ p["w_up"], kind)
+        else:
+            h = activate(x @ p["w_in"], None, kind)
+        return h @ p["w_out"]
 
 
 def init_mlp(key, d: int, f: int, kind: str, dtype=jnp.float32):
@@ -222,23 +223,24 @@ def attention(
     B, T, _ = x.shape
     hq, hkv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
 
-    q = _split_heads((x @ p["wq"].astype(dt)), hq, hd)
     read_only = cache is not None and cache_pos is None
-    if read_only:                                    # read-only (cross-attn)
-        k, v = cache["k"].astype(dt), cache["v"].astype(dt)
-    else:
-        src = x if kv_x is None else kv_x
-        k = _split_heads((src @ p["wk"].astype(dt)), hkv, hd)
-        v = _split_heads((src @ p["wv"].astype(dt)), hkv, hd)
+    with jax.named_scope("qkv_proj"):
+        q = _split_heads((x @ p["wq"].astype(dt)), hq, hd)
+        if read_only:                                # read-only (cross-attn)
+            k, v = cache["k"].astype(dt), cache["v"].astype(dt)
+        else:
+            src = x if kv_x is None else kv_x
+            k = _split_heads((src @ p["wk"].astype(dt)), hkv, hd)
+            v = _split_heads((src @ p["wv"].astype(dt)), hkv, hd)
 
-    if "q_norm" in p:
-        q = rms_norm(q, p["q_norm"], cfg.norm_eps)
-        if not read_only:
-            k = rms_norm(k, p["k_norm"], cfg.norm_eps)
-    if kv_x is None and not read_only:
-        # self-attention: rotate (cross-attn and read-only skip)
-        q = apply_rope(q, positions, cfg.rope_theta)
-        k = apply_rope(k, positions, cfg.rope_theta)
+        if "q_norm" in p:
+            q = rms_norm(q, p["q_norm"], cfg.norm_eps)
+            if not read_only:
+                k = rms_norm(k, p["k_norm"], cfg.norm_eps)
+        if kv_x is None and not read_only:
+            # self-attention: rotate (cross-attn and read-only skip)
+            q = apply_rope(q, positions, cfg.rope_theta)
+            k = apply_rope(k, positions, cfg.rope_theta)
     # cache entries hold post-norm, post-rope K (what decode appends)
     new_kv = None if read_only else (k, v)
 
@@ -270,25 +272,29 @@ def attention(
         else:
             cpm = (cp + jnp.arange(T))[None]
         cpm = jnp.broadcast_to(cpm, (B, T))
-        blk = jnp.take_along_axis(block_tables, cpm // bs, axis=1)  # (B, T)
-        off = cpm % bs
-        ck = cache["k"].at[blk, off].set(k.astype(cache["k"].dtype))
-        cv = cache["v"].at[blk, off].set(v.astype(cache["v"].dtype))
+        with jax.named_scope("kv_write"):
+            blk = jnp.take_along_axis(block_tables, cpm // bs,
+                                      axis=1)                   # (B, T)
+            off = cpm % bs
+            ck = cache["k"].at[blk, off].set(k.astype(cache["k"].dtype))
+            cv = cache["v"].at[blk, off].set(v.astype(cache["v"].dtype))
         from repro.kernels import ops as kernel_ops
 
         if _ATTN_TAP is not None:
             _ATTN_TAP.append((q, ck, cv, block_tables, cpm))
-        if T == 1:
-            o = kernel_ops.paged_attention(
-                q[:, 0], ck, cv, block_tables, cpm[:, 0],
-                use_pallas=cfg.use_pallas,
-                attn_approx=cfg.attn_approx, window=cfg.attn_window)
-        else:
-            o = kernel_ops.paged_attention(
-                q, ck, cv, block_tables, cpm, use_pallas=cfg.use_pallas,
-                attn_approx=cfg.attn_approx, window=cfg.attn_window)
-        out = o.reshape(B, T, hq * hd).astype(dt)
-        return out @ p["wo"].astype(dt), {"k": ck, "v": cv}
+        with jax.named_scope("attention"):
+            if T == 1:
+                o = kernel_ops.paged_attention(
+                    q[:, 0], ck, cv, block_tables, cpm[:, 0],
+                    use_pallas=cfg.use_pallas,
+                    attn_approx=cfg.attn_approx, window=cfg.attn_window)
+            else:
+                o = kernel_ops.paged_attention(
+                    q, ck, cv, block_tables, cpm, use_pallas=cfg.use_pallas,
+                    attn_approx=cfg.attn_approx, window=cfg.attn_window)
+        with jax.named_scope("out_proj"):
+            out = o.reshape(B, T, hq * hd).astype(dt) @ p["wo"].astype(dt)
+        return out, {"k": ck, "v": cv}
 
     extra = None
     if cache is not None and cache_pos is not None:

@@ -115,6 +115,7 @@ from typing import Callable, Dict, List, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.configs.base import ModelConfig
 from repro.models import api, lm
@@ -205,24 +206,27 @@ def _jitted_step(cfg: ModelConfig, samplers: tuple, treedef,
         # padding repeats the last (token, position), so column -1 is
         # identical to column w-1 for every width-w window.
         hl = h[:, -1] if h.ndim == 3 else h
-        outs = tuple(s.head(params, cfg, hl[r])
-                     for s, r in zip(samplers, rows))
+        with jax.named_scope("head"):
+            outs = tuple(s.head(params, cfg, hl[r])
+                         for s, r in zip(samplers, rows))
         if spec_pallas is not None:
             w = sampler_mod._head_weight(params, cfg)
-            if spec_pallas == "sharded":
-                # vocab-sharded verify unit: per-position per-shard
-                # comparator + (val, idx) combine — same accept rule,
-                # O(shards) pairs per position on the wire.
-                from repro.core import reduced_softmax
+            with jax.named_scope("verify"):
+                if spec_pallas == "sharded":
+                    # vocab-sharded verify unit: per-position per-shard
+                    # comparator + (val, idx) combine — same accept
+                    # rule, O(shards) pairs per position on the wire.
+                    from repro.core import reduced_softmax
 
-                outs = outs + (reduced_softmax.sharded_verify_draft(
-                    h[spec_rows], w, spec_cand, env.current_mesh(),
-                    use_pallas=cfg.use_pallas),)
-            else:
-                from repro.kernels import ops as kernel_ops
+                    outs = outs + (reduced_softmax.sharded_verify_draft(
+                        h[spec_rows], w, spec_cand, env.current_mesh(),
+                        use_pallas=cfg.use_pallas),)
+                else:
+                    from repro.kernels import ops as kernel_ops
 
-                outs = outs + (kernel_ops.verify_draft(
-                    h[spec_rows], w, spec_cand, use_pallas=spec_pallas),)
+                    outs = outs + (kernel_ops.verify_draft(
+                        h[spec_rows], w, spec_cand,
+                        use_pallas=spec_pallas),)
         new_pools, new_denses = [], []
         for m, leaf in zip(paged_mask, jax.tree.flatten(new_cache)[0]):
             new_pools.append(leaf if m else None)
@@ -542,13 +546,20 @@ class ServeEngine:
         # cached run (and the tokens they skipped); prefill_tokens counts
         # prompt tokens ACTUALLY prefilled (one-shot scatters + chunk
         # rows) — the denominator of the prefix-cache savings metric.
+        # step_tokens counts the real query tokens the step programs
+        # (fused or multi-step) were given, step_slots the query slots
+        # they ran (padded rows x T, x the stride on the multi-step
+        # path): their ratio is how full the steps were.  step_host_ns
+        # is the host time of ``step()`` outside its wait for results.
         self.stats = {"prefills": 0, "prefill_chunks": 0, "decode_steps": 0,
                       "iterations": 0, "fused_rows": 0, "completed": 0,
                       "deferred": 0, "preemptions": 0, "cancelled": 0,
                       "drafted": 0, "accepted": 0, "acceptance_rate": 0.0,
                       "host_syncs": 0, "emitted_tokens": 0,
                       "prefix_hits": 0, "prefix_hit_tokens": 0,
-                      "prefill_tokens": 0}
+                      "prefill_tokens": 0, "step_tokens": 0,
+                      "step_slots": 0, "step_host_ns": 0}
+        self._sync_s = 0.0       # this step's wait in _sync
         # per-request TTFT samples (ms, submit -> first token), feeding
         # the percentile columns of ``snapshot()`` / GET /v1/stats.
         self._ttft_ms: List[float] = []
@@ -585,6 +596,7 @@ class ServeEngine:
         s["cow_copies"] = self.store.cow_copies
         s["shared_blocks"] = self.store.allocator.n_shared
         s["peak_in_use"] = self.store.allocator.peak_in_use
+        s["live_peak"] = self.store.live_peak
         if self._ttft_ms:
             t = np.asarray(self._ttft_ms)
             s["ttft_ms_p50"] = float(np.percentile(t, 50))
@@ -594,6 +606,7 @@ class ServeEngine:
         return s
 
     # -- queue management ----------------------------------------------------
+    @functools.partial(jax.profiler.annotate_function, name="serve.submit")
     def submit(self, req: Request):
         if req.params is None:
             # legacy surface: synthesize the typed params from the loose
@@ -896,9 +909,27 @@ class ServeEngine:
         every active slot — decode rows, speculative windows and
         prefill-chunk rows in the same jitted call
         (``scheduler='cohort'`` partitions by (position, head) first —
-        the PR 2 baseline)."""
+        the position-cohort baseline).
+
+        Traced as the host span ``serve.step`` (args: the dispatched
+        shape) with the children ``serve.admit``, ``serve.plan``,
+        ``serve.operands``, ``serve.dispatch``, ``serve.sync`` and
+        ``serve.emit``."""
+        t0 = time.perf_counter()
+        self._sync_s = 0.0
+        with TraceAnnotation("serve.step") as span:
+            busy, shape = self._iterate()
+            if shape:
+                span.set_metadata(**shape)
+        self.stats["step_host_ns"] += round(
+            (time.perf_counter() - t0 - self._sync_s) * 1e9)
+        return busy
+
+    def _iterate(self):
+        """``step``'s body: ``(more work, the dispatched shape)``."""
         self.stats["iterations"] += 1
-        self._admit()
+        with TraceAnnotation("serve.admit"):
+            self._admit()
         active = [i for i, s in enumerate(self.slots) if s is not None]
         if not active:
             if self.queue and not self.store.can_admit(
@@ -912,35 +943,55 @@ class ServeEngine:
                     f"prompt) can never be admitted: pool of "
                     f"{self.store.allocator.num_blocks} x "
                     f"{self.store.block_size}-token blocks is too small")
-            return bool(self.queue)
+            return bool(self.queue), None
         # capacity pass at each slot's OWN position; a later slot's
         # ensure may have PREEMPTED an earlier accepted one (keep= only
         # shields the current slot): re-validate afterwards.
-        active = [i for i in active
-                  if self._ensure_blocks(i, int(self.slot_pos[i]))]
-        active = [i for i in active if self.slots[i] is not None]
+        with TraceAnnotation("serve.plan"):
+            active = [i for i in active
+                      if self._ensure_blocks(i, int(self.slot_pos[i]))]
+            active = [i for i in active if self.slots[i] is not None]
         if not active:
-            return True
+            return True, None
         if self.scheduler == "cohort":
             parts: Dict[tuple, list] = {}
             for i in active:
                 dev = self.slots[i].sampler.device_form()
                 parts.setdefault((int(self.slot_pos[i]), repr(dev)),
                                  []).append(i)
-            for key in sorted(parts):
-                self._decode_rows(parts[key])
-        elif (self.host_stride is not None
-              and not any(self._prefilling(i) for i in active)):
+            shapes = [self._decode_rows(parts[key]) for key in sorted(parts)]
+            shape = {k: sum(s[k] for s in shapes) for k in shapes[0]}
+            shape["T"] = max(s["T"] for s in shapes)
+            return True, shape
+        if (self.host_stride is not None
+                and not any(self._prefilling(i) for i in active)):
             # the device-resident multi-step dispatch: one host sync
             # for up to host_stride tokens per row.  Iterations with a
             # mid-prefill slot fall back to the legacy single step (the
             # loop has no chunk rows) — still keyed, so generations
             # stay stride-invariant; admission/preemption above already
             # synchronized at this stride boundary.
-            self._decode_multi(active)
-        else:
-            self._decode_rows(active)
-        return True
+            return True, self._decode_multi(active)
+        return True, self._decode_rows(active)
+
+    def _sync(self, outs) -> list:
+        """Wait for a dispatch's outputs on the host (``serve.sync``)."""
+        t = time.perf_counter()
+        with TraceAnnotation("serve.sync"):
+            host = [_to_host(o) for o in outs]
+        self._sync_s += time.perf_counter() - t
+        return host
+
+    def _count_step(self, n_real: int, n_padded: int, T: int,
+                    n_chunk: int, tokens: int, slots: int) -> dict:
+        """Count a dispatched step program; its shape for the span."""
+        self.stats["decode_steps"] += 1
+        self.stats["host_syncs"] += 1
+        self.stats["fused_rows"] += n_real
+        self.stats["step_tokens"] += tokens
+        self.stats["step_slots"] += slots
+        return {"rows": n_real, "padded_rows": n_padded, "T": T,
+                "chunk_rows": n_chunk, "tokens": tokens}
 
     def _propose(self, i: int) -> list:
         """Draft tokens for slot ``i`` this step (possibly none): ask the
@@ -1044,10 +1095,39 @@ class ServeEngine:
         (``store.rewind`` returns surplus blocks).
         """
         n_real = len(rows)
-        drafts = {i: self._propose(i) for i in rows}
-        n_decode_tokens = sum(1 + len(drafts[i]) for i in rows
-                              if not self._prefilling(i))
-        chunks = self._plan_chunks(rows, n_decode_tokens)
+        with TraceAnnotation("serve.plan"):
+            drafts = {i: self._propose(i) for i in rows}
+            n_decode_tokens = sum(1 + len(drafts[i]) for i in rows
+                                  if not self._prefilling(i))
+            chunks = self._plan_chunks(rows, n_decode_tokens)
+        with TraceAnnotation("serve.operands"):
+            fn, args, where, order, T, padded = self._step_operands(
+                rows, drafts, chunks)
+        with TraceAnnotation("serve.dispatch"):
+            with env.use_mesh(self.mesh):
+                outs, new_pools, new_denses = fn(*args)
+            self.store.write_back(
+                rows, new_pools,
+                [None if d is None else d[:, :n_real] for d in new_denses])
+        shape = self._count_step(
+            n_real, len(padded), T, len(chunks),
+            sum(chunks[i][1] if i in chunks else 1 + len(drafts[i])
+                for i in rows),
+            len(padded) * T)
+        # one device->host sync per head group, not per slot
+        host_outs = self._sync(outs)
+        host = dict(zip(order, host_outs))
+        spec_host = host_outs[len(order)] if len(outs) > len(order) else None
+        with TraceAnnotation("serve.emit"):
+            self._emit_rows(rows, where, chunks, drafts, host, spec_host)
+        return shape
+
+    def _step_operands(self, rows, drafts, chunks):
+        """The fused step's program and operands for ``rows``: host
+        arrays, block table and row sets, sent to the device.  Returns
+        ``(fn, args, where, order, T, padded)``; ``where[r]`` is padded
+        row ``r``'s (head group, offset)."""
+        n_real = len(rows)
         width = max([1 + len(drafts[i]) for i in rows]
                     + [w for _, w in chunks.values()])
         T = _pow2(width)
@@ -1120,32 +1200,21 @@ class ServeEngine:
         fn = _jitted_step(self.cfg, tuple(order), self.store.treedef,
                           tuple(self.store.paged_mask), self.mesh,
                           spec_pallas)
-        with env.use_mesh(self.mesh):
-            if spec_group:
-                outs, new_pools, new_denses = fn(
-                    self.params, jnp.asarray(toks), self.store.pools,
-                    denses, None if btab is None else jnp.asarray(btab),
-                    jnp.asarray(posm), row_sets, spec_rows_op,
-                    spec_cand_op)
-            else:
-                # (B,) positions at T == 1 (the pure-decode fast path,
-                # same compiled shapes as ever); (B, T) whenever any
-                # window — draft or chunk — widens the step.
-                outs, new_pools, new_denses = fn(
-                    self.params, jnp.asarray(toks), self.store.pools,
-                    denses, None if btab is None else jnp.asarray(btab),
-                    jnp.asarray(posm if T > 1 else posm[:, 0]), row_sets)
-        self.stats["decode_steps"] += 1
-        self.stats["host_syncs"] += 1
-        self.stats["fused_rows"] += n_real
-        self.store.write_back(
-            rows, new_pools,
-            [None if d is None else d[:, :n_real] for d in new_denses])
-        # one device->host sync per head group, not per slot
-        host = {dev: _to_host(o) for dev, o in zip(order, outs)}
-        spec_host = _to_host(outs[len(order)]) if spec_group else None
-        for r in range(n_real):
-            i = padded[r]
+        # (B,) positions at T == 1 (the pure-decode fast path, same
+        # compiled shapes as ever); (B, T) whenever any window — draft
+        # or chunk — widens the step.
+        args = (self.params, jnp.asarray(toks), self.store.pools, denses,
+                None if btab is None else jnp.asarray(btab),
+                jnp.asarray(posm if T > 1 else posm[:, 0]), row_sets)
+        if spec_group:
+            args += (spec_rows_op, spec_cand_op)
+        return fn, args, where, order, T, padded
+
+    def _emit_rows(self, rows, where, chunks, drafts, host, spec_host):
+        """Emit what a fused step produced for its real ``rows``:
+        advance chunk cursors (a final chunk emits the first token),
+        drain verified draft runs, emit decode tokens."""
+        for r, i in enumerate(rows):
             dev, off = where[r]
             req = self.slots[i]
             if i in chunks:
@@ -1216,18 +1285,40 @@ class ServeEngine:
         """
         K = self.host_stride
         caps: Dict[int, int] = {}
-        for i in rows:
-            req = self.slots[i]
-            pos = int(self.slot_pos[i])
-            cap = max(1, min(K, req.max_new_tokens - len(req.generated),
-                             self.max_len - 1 - pos))
-            while cap > 1 and not self.store.can_grow(i, pos + cap - 1,
-                                                      write_start=pos):
-                cap -= 1
-            if cap > 1 and not self.store.ensure_capacity(i, pos + cap - 1,
-                                                          write_start=pos):
-                cap = 1           # lost a race; ``pos`` itself is covered
-            caps[i] = cap
+        with TraceAnnotation("serve.plan"):
+            for i in rows:
+                req = self.slots[i]
+                pos = int(self.slot_pos[i])
+                cap = max(1, min(K, req.max_new_tokens - len(req.generated),
+                                 self.max_len - 1 - pos))
+                while cap > 1 and not self.store.can_grow(
+                        i, pos + cap - 1, write_start=pos):
+                    cap -= 1
+                if cap > 1 and not self.store.ensure_capacity(
+                        i, pos + cap - 1, write_start=pos):
+                    cap = 1       # lost a race; ``pos`` itself is covered
+                caps[i] = cap
+        with TraceAnnotation("serve.operands"):
+            fn, args, padded = self._multi_operands(rows, caps)
+        n_real = len(rows)
+        with TraceAnnotation("serve.dispatch"):
+            with env.use_mesh(self.mesh):
+                (out, emitted, new_keys), new_pools, new_denses = fn(*args)
+            self.store.write_back(
+                rows, new_pools,
+                [None if d is None else d[:, :n_real] for d in new_denses])
+        out_h, emitted_h, keys_h = self._sync((out, emitted, new_keys))
+        shape = self._count_step(n_real, len(padded), K, 0,
+                                 int(emitted_h[:n_real].sum()),
+                                 len(padded) * K)
+        with TraceAnnotation("serve.emit"):
+            self._drain_multi(rows, out_h, emitted_h, keys_h)
+        return shape
+
+    def _multi_operands(self, rows, caps):
+        """The multi-step program and its operands for ``rows`` with
+        per-row emit ``caps``: ``(fn, args, padded)``."""
+        K = self.host_stride
         n_real = len(rows)
         padded = rows + [rows[0]] * (_pow2(n_real) - n_real)
         groups: Dict[Sampler, list] = {}
@@ -1257,23 +1348,15 @@ class ServeEngine:
             self.cfg, tuple(order), self.store.treedef,
             tuple(self.store.paged_mask), K,
             -1 if self.eos_id is None else int(self.eos_id), self.mesh)
-        with env.use_mesh(self.mesh):
-            (out, emitted, new_keys), new_pools, new_denses = fn(
-                self.params, jnp.asarray(toks), self.store.pools, denses,
+        args = (self.params, jnp.asarray(toks), self.store.pools, denses,
                 None if btab is None else jnp.asarray(btab),
                 jnp.asarray(pos_arr), jnp.asarray(keys),
                 jnp.asarray(emit_caps), row_sets)
-        self.stats["decode_steps"] += 1
-        self.stats["host_syncs"] += 1
-        self.stats["fused_rows"] += n_real
-        self.store.write_back(
-            rows, new_pools,
-            [None if d is None else d[:, :n_real] for d in new_denses])
-        out_h = np.asarray(out)
-        emitted_h = np.asarray(emitted)
-        keys_h = np.asarray(new_keys)
-        for r in range(n_real):
-            i = padded[r]
+        return fn, args, padded
+
+    def _drain_multi(self, rows, out_h, emitted_h, keys_h):
+        """Emit a multi-step block row by row, trimming at a stop."""
+        for r, i in enumerate(rows):
             req = self.slots[i]
             if req is None:
                 # a consumer cancelled this slot while an earlier row

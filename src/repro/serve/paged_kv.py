@@ -51,6 +51,7 @@ greedy outputs — asserted token-exactly by tests/test_serve_paged.py.
 """
 from __future__ import annotations
 
+import collections
 import math
 from typing import List, Optional
 
@@ -310,6 +311,10 @@ class PagedKVStore:
         self.prefix_trie = PrefixTrie(block_size)
         self.cow_copies = 0
         self.prefix_evictions = 0
+        # block -> number of slot tables mapping it, kept where tables
+        # change (never by walking the trie): its size is ``live_blocks``
+        self._live = collections.Counter()
+        self.live_peak = 0
 
     @property
     def any_paged(self) -> bool:
@@ -352,7 +357,34 @@ class PagedKVStore:
             "blocks_reclaimable": self.reclaimable_blocks,
             "cow_copies": self.cow_copies,
             "prefix_evictions": self.prefix_evictions,
+            "live_blocks": self.live_blocks,
+            "live_peak": self.live_peak,
         }
+
+    # -- live blocks ---------------------------------------------------------
+    @property
+    def live_blocks(self) -> int:
+        """Distinct blocks that live slots' tables map: what requests in
+        flight hold, without the cached blocks only the trie keeps."""
+        return len(self._live)
+
+    def reset_live_peak(self) -> None:
+        """Start ``live_peak`` over from the blocks live now (a
+        measurement window's opening)."""
+        self.live_peak = self.live_blocks
+
+    def _map(self, blocks) -> None:
+        for b in blocks:
+            self._live[b] += 1
+        self.live_peak = max(self.live_peak, len(self._live))
+
+    def _unmap(self, blocks) -> None:
+        for b in blocks:
+            n = self._live[b] - 1
+            if n > 0:
+                self._live[b] = n
+            else:
+                self._live.pop(b, None)
 
     # -- block accounting ----------------------------------------------------
     def blocks_for(self, n_tokens: int) -> int:
@@ -445,6 +477,7 @@ class PagedKVStore:
         assert not self.slot_blocks[slot], (slot, self.slot_blocks[slot])
         nb = self.blocks_for(prompt_len)
         self.slot_blocks[slot] = self._alloc(nb) if nb else []
+        self._map(self.slot_blocks[slot])
         return self.slot_blocks[slot]
 
     def install_prefill(self, slot: int, new_pools, dense_leaves) -> None:
@@ -472,6 +505,7 @@ class PagedKVStore:
         nb = self.blocks_for(prompt_len)
         blocks = self._alloc(nb) if nb else []
         self.slot_blocks[slot] = blocks
+        self._map(blocks)
         bs = self.block_size
         for j, (m, leaf) in enumerate(zip(self.paged_mask, cache1_leaves)):
             if m:
@@ -500,6 +534,8 @@ class PagedKVStore:
                 self.pools[j] = self.pools[j].at[:, new].set(
                     self.pools[j][:, old])
         self.slot_blocks[slot][k] = new
+        self._unmap([old])
+        self._map([new])
         self.allocator.free([old])
         self.cow_copies += 1
 
@@ -538,7 +574,9 @@ class PagedKVStore:
         if self._effective_free() < max(0, need - have) + len(cow):
             return False
         if need > have:
-            self.slot_blocks[slot].extend(self._alloc(need - have))
+            grown = self._alloc(need - have)
+            self.slot_blocks[slot].extend(grown)
+            self._map(grown)
         for k in cow:
             self._cow(slot, k)
         return True
@@ -578,6 +616,7 @@ class PagedKVStore:
         extra = self.slot_blocks[slot][keep:]
         if extra:
             del self.slot_blocks[slot][keep:]
+            self._unmap(extra)
             self.allocator.free(extra)
         if self.slot_blocks[slot]:
             self._cow(slot, min(keep, len(self.slot_blocks[slot])) - 1)
@@ -600,6 +639,7 @@ class PagedKVStore:
         if blocks:
             self.allocator.incref(blocks)
             self.slot_blocks[slot] = list(blocks)
+            self._map(blocks)
         return hit_len
 
     def release(self, slot: int,
@@ -613,6 +653,7 @@ class PagedKVStore:
         only its suffix."""
         blocks = self.slot_blocks[slot]
         self.slot_blocks[slot] = []
+        self._unmap(blocks)
         if publish_tokens is not None and self.any_paged and blocks:
             nb = min(len(publish_tokens) // self.block_size, len(blocks))
             if nb:

@@ -62,12 +62,13 @@ _SUM_KEYS = (
     "fused_rows", "completed", "deferred", "preemptions", "cancelled",
     "drafted", "accepted", "host_syncs", "emitted_tokens",
     "prefix_hits", "prefix_hit_tokens", "prefill_tokens",
+    "step_tokens", "step_slots", "step_host_ns",
     "queue_depth", "active_slots", "cow_copies", "shared_blocks",
 )
 # peaks take the max over replicas: the worst single-pool pressure seen
 # anywhere, NOT a fleet total (pools are disjoint, so a sum would mix
 # high-watermarks that never coexisted).
-_MAX_KEYS = ("peak_in_use",)
+_MAX_KEYS = ("peak_in_use", "live_peak")
 
 
 def aggregate_engine_stats(snaps: Sequence[dict],
@@ -111,17 +112,19 @@ def aggregate_engine_stats(snaps: Sequence[dict],
 
 def aggregate_kv(usages: Sequence[dict]) -> dict:
     """Merge per-replica ``store.usage()`` dicts: block counts sum
-    (pools are disjoint), ``peak_in_use`` maxes, layout/block_size come
-    from the first replica (homogeneous)."""
+    (pools are disjoint), peaks (``peak_in_use``, ``live_peak``) max,
+    layout/block_size come from the first replica (homogeneous)."""
     if not usages:
         return {}
     out = {"layout": usages[0]["layout"],
            "block_size": usages[0]["block_size"]}
     for k in ("num_blocks", "blocks_free", "blocks_in_use", "paged_leaves",
               "dense_leaves", "shared_blocks", "prefix_blocks",
-              "blocks_reclaimable", "cow_copies", "prefix_evictions"):
+              "blocks_reclaimable", "cow_copies", "prefix_evictions",
+              "live_blocks"):
         out[k] = sum(int(u.get(k, 0)) for u in usages)
-    out["peak_in_use"] = max(int(u.get("peak_in_use", 0)) for u in usages)
+    for k in ("peak_in_use", "live_peak"):
+        out[k] = max(int(u.get(k, 0)) for u in usages)
     return out
 
 

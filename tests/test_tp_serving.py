@@ -88,12 +88,16 @@ def _prompts(cfg, n=4, seed=3):
 def test_aggregate_engine_stats_merge_rules():
     a = {"emitted_tokens": 10, "decode_steps": 5, "drafted": 4,
          "accepted": 3, "host_syncs": 5, "peak_in_use": 7,
+         "step_tokens": 40, "step_slots": 64, "live_peak": 3,
          "attn_approx": "exact", "attn_window": None}
     b = {"emitted_tokens": 6, "decode_steps": 3, "drafted": 0,
-         "accepted": 0, "host_syncs": 3, "peak_in_use": 2}
+         "accepted": 0, "host_syncs": 3, "peak_in_use": 2,
+         "step_tokens": 9, "step_slots": 16, "live_peak": 5}
     agg = aggregate_engine_stats([a, b], ttft_pools=[[10.0, 30.0], [20.0]])
     assert agg["emitted_tokens"] == 16          # counters sum
+    assert agg["step_tokens"] == 49 and agg["step_slots"] == 80
     assert agg["peak_in_use"] == 7              # peaks max, never sum
+    assert agg["live_peak"] == 5
     # ratios recomputed from summed terms — NOT averaged (replica b's
     # 0/0 must not dilute replica a's 3/4)
     assert agg["acceptance_rate"] == 3 / 4
@@ -108,13 +112,17 @@ def test_aggregate_engine_stats_merge_rules():
 
 def test_aggregate_kv_merge_rules():
     u1 = {"layout": "paged", "block_size": 8, "num_blocks": 16,
-          "blocks_in_use": 4, "peak_in_use": 9}
+          "blocks_in_use": 4, "peak_in_use": 9, "live_blocks": 3,
+          "live_peak": 4}
     u2 = {"layout": "paged", "block_size": 8, "num_blocks": 16,
-          "blocks_in_use": 2, "peak_in_use": 3}
+          "blocks_in_use": 2, "peak_in_use": 3, "live_blocks": 2,
+          "live_peak": 6}
     agg = aggregate_kv([u1, u2])
     assert agg["num_blocks"] == 32              # disjoint pools sum
     assert agg["blocks_in_use"] == 6
+    assert agg["live_blocks"] == 5
     assert agg["peak_in_use"] == 9              # worst single pool
+    assert agg["live_peak"] == 6
     assert agg["block_size"] == 8
 
 
@@ -148,8 +156,12 @@ def test_router_least_loaded_and_order(setup):
     assert [r.served for r in router.replicas] == [2, 2]
     # the aggregate invariant, through the real payload
     p = router.stats_payload()
-    for k in ("emitted_tokens", "decode_steps", "completed"):
+    for k in ("emitted_tokens", "decode_steps", "completed", "step_tokens",
+              "step_slots"):
         assert p["engine"][k] == sum(r["engine"][k] for r in p["replicas"])
+    assert p["engine"]["step_tokens"] > 0
+    assert p["engine"]["live_peak"] == \
+        max(r["engine"]["live_peak"] for r in p["replicas"])
     assert p["engine"]["emitted_tokens"] == 16
     assert p["kv"]["num_blocks"] == \
         sum(r["kv"]["num_blocks"] for r in p["replicas"])
