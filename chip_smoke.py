@@ -10,11 +10,14 @@ from ``--seed``.  Requests go through the entry points a user calls:
 the code ``python -m repro.launch.serve --arch qwen3-0.6b --serve-http``
 runs.  Every phase runs in this one process, which alone holds the chip.
 
-  a  default engine: fused scheduler, paged KV, greedy comparator head
+  a  default engine: fused scheduler, paged KV, greedy comparator head;
+     its paged attention is the compiled kernel on the chip (the
+     dispatch's own rule), the jnp reference off it
   b  the same LLM behind ``make_server(port=0)``: streamed and plain
      ``POST /v1/completions`` and ``GET /v1/stats``; tokens equal a's
   c  the softmax baseline head; Theorem 1: tokens equal a's
-  d  compiled Pallas kernels (``use_pallas``, chunk_size 64, spec_k 4):
+  d  compiled Pallas kernels for the heads and verify bank too
+     (``use_pallas``, chunk_size 64, spec_k 4):
      ``tpu_custom_call`` in the compiled step; tokens equal a's
   e  device-resident decode (host_stride 8); tokens equal a's
 

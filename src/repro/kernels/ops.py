@@ -5,7 +5,10 @@ in ONE place (``resolve_flags``):
 
   - ``use_pallas=False`` (default) -> the pure-jnp oracle (ref.py). This
     is what the dry-run lowers, so roofline numbers are XLA's, not the
-    interpreter's.
+    interpreter's.  One exception: ``paged_attention`` runs its compiled
+    kernel on a TPU backend wherever that kernel applies
+    (``paged_attention.kernel_runs``): the oracle's dense gather is what
+    the serving step cannot afford there.
   - ``use_pallas=True, interpret=None`` -> auto: the real VMEM-tiled
     kernel on TPU, Pallas interpret mode everywhere else (CPU CI).
   - explicit ``interpret=True/False`` is honored as given (tests pin
@@ -113,28 +116,38 @@ def paged_attention(q, k_pool, v_pool, block_tables, positions, *,
                     window: Optional[int] = None):
     """Ragged decode attention straight off a block-paged KV pool.
 
-    q (B, Hq, hd) — or (B, T, Hq, hd) for a MULTI-TOKEN (speculative)
-    step; pools (num_blocks, block_size, Hkv, hd); block_tables (B, nb)
-    i32; positions (B,) i32 — or (B, T) i32 per-query positions in the
-    multi-token form — each query attends over its own kv positions <=
-    its position (a scalar broadcasts) -> (B, Hq, hd) / (B, T, Hq, hd).
-    The Pallas kernel reads pool blocks in place (block table drives the
-    index maps; the per-row position is a scalar-prefetch operand); the
-    ref path is the dense decode math over the gathered view —
-    token-exact against the dense cache layout.
+    q (B, Hq, hd) — or (B, T, Hq, hd) for a MULTI-TOKEN step (draft
+    window or prefill chunk); pools (num_blocks, block_size, Hkv, hd);
+    block_tables (B, nb) i32; positions (B,) i32 — or (B, T) i32
+    per-query positions in the multi-token form, ascending within a row
+    and padded by repeating the row's last position — each query attends
+    over its own kv positions <= its position (a scalar broadcasts)
+    -> (B, Hq, hd) / (B, T, Hq, hd).
+
+    Dispatch (``paged_attention.kernel_runs``): the Pallas kernel reads
+    each row's live pool blocks in place and computes a decode row's one
+    real query once; it runs when ``use_pallas`` asks (interpret mode
+    off the TPU), and, with ``use_pallas=False``, compiled on a TPU
+    backend whenever the mode compiles (exact, pseudo, maxonly), T <=
+    128 and the pool is not split across devices (tp = 1).  Everything
+    else — the CPU, tp > 1 pools, T > 128, base2/pwl — runs
+    ``ref.paged_attention``, the dense decode math over the gathered
+    view, token-exact against the dense cache layout.
 
     ``attn_approx`` picks the score function from the
     ``core.attn_approx`` catalog ('exact' | 'base2' | 'pseudo' | 'pwl' |
     'maxonly'); ``window`` caps each query to its last ``window`` kv
     positions.  Both are STATIC modes resolved here at trace time
     (loop-safe, like the flag pair) and honored identically by both
-    twins; the defaults are bit-identical to the pre-catalog op.
+    twins.
     """
     use_pallas, interpret = resolve_flags(use_pallas, interpret)
     attn_approx, window = attn_approx_mod.resolve(attn_approx, window)
-    if use_pallas:
+    t = q.shape[1] if q.ndim == 4 else 1
+    if _pa.kernel_runs(t, use_pallas=use_pallas, attn_approx=attn_approx):
         return _pa.paged_attention(q, k_pool, v_pool, block_tables,
-                                   positions, interpret=interpret,
+                                   positions,
+                                   interpret=use_pallas and interpret,
                                    attn_approx=attn_approx, window=window)
     return ref.paged_attention(q, k_pool, v_pool, block_tables, positions,
                                attn_approx=attn_approx, window=window)

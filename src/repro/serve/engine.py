@@ -118,6 +118,7 @@ import numpy as np
 from jax.profiler import TraceAnnotation
 
 from repro.configs.base import ModelConfig
+from repro.kernels import paged_attention as paged_kernel
 from repro.models import api, lm
 from repro.parallel import env
 from repro.serve import sampler as sampler_mod
@@ -551,6 +552,13 @@ class ServeEngine:
         # they ran (padded rows x T, x the stride on the multi-step
         # path): their ratio is how full the steps were.  step_host_ns
         # is the host time of ``step()`` outside its wait for results.
+        # attn_kernel_steps counts step-program iterations whose paged
+        # attention ran the in-place kernel (``paged_kernel.kernel_runs``,
+        # the dispatch's own rule); attn_blocks_read the pool blocks
+        # their attention read over every padded row (each row's live
+        # blocks under the kernel, the whole table under the
+        # reference's gather); attn_blocks_table padded rows x table
+        # width: their ratio is the share of the table attention read.
         self.stats = {"prefills": 0, "prefill_chunks": 0, "decode_steps": 0,
                       "iterations": 0, "fused_rows": 0, "completed": 0,
                       "deferred": 0, "preemptions": 0, "cancelled": 0,
@@ -558,7 +566,9 @@ class ServeEngine:
                       "host_syncs": 0, "emitted_tokens": 0,
                       "prefix_hits": 0, "prefix_hit_tokens": 0,
                       "prefill_tokens": 0, "step_tokens": 0,
-                      "step_slots": 0, "step_host_ns": 0}
+                      "step_slots": 0, "step_host_ns": 0,
+                      "attn_kernel_steps": 0, "attn_blocks_read": 0,
+                      "attn_blocks_table": 0}
         self._sync_s = 0.0       # this step's wait in _sync
         # per-request TTFT samples (ms, submit -> first token), feeding
         # the percentile columns of ``snapshot()`` / GET /v1/stats.
@@ -993,6 +1003,23 @@ class ServeEngine:
         return {"rows": n_real, "padded_rows": n_padded, "T": T,
                 "chunk_rows": n_chunk, "tokens": tokens}
 
+    def _count_attention(self, last_pos, nb: int, T: int,
+                         iterations: int = 1) -> None:
+        """Count the paged attention of ``iterations`` step-program
+        iterations of query width ``T`` over a table ``nb`` wide, whose
+        padded rows sit at ``last_pos`` (their largest query position;
+        one entry per row per iteration)."""
+        last_pos = np.asarray(last_pos)
+        self.stats["attn_blocks_table"] += last_pos.size * nb
+        if paged_kernel.kernel_runs(T, use_pallas=self.cfg.use_pallas,
+                                    attn_approx=self.cfg.attn_approx,
+                                    mesh=self.mesh):
+            self.stats["attn_kernel_steps"] += iterations
+            self.stats["attn_blocks_read"] += int(paged_kernel.live_blocks(
+                last_pos, self.store.block_size, nb).sum())
+        else:
+            self.stats["attn_blocks_read"] += last_pos.size * nb
+
     def _propose(self, i: int) -> list:
         """Draft tokens for slot ``i`` this step (possibly none): ask the
         Drafter for up to the request's remaining speculation budget,
@@ -1181,6 +1208,8 @@ class ServeEngine:
             posm[r, :w] = base + np.arange(w)
             posm[r, w:] = base + w - 1   # identical value, identical cell
         btab = self.store.block_table(padded, posm[:, -1])
+        if btab is not None:
+            self._count_attention(posm[:, -1], btab.shape[1], T)
         denses = self.store.dense_sub(padded)
         spec_pallas = spec_rows_op = spec_cand_op = None
         if spec_group:
@@ -1308,6 +1337,15 @@ class ServeEngine:
                 rows, new_pools,
                 [None if d is None else d[:, :n_real] for d in new_denses])
         out_h, emitted_h, keys_h = self._sync((out, emitted, new_keys))
+        btab = args[4]
+        if btab is not None:
+            # the loop ran while any row emitted; a row attends at its
+            # start position plus the tokens it has emitted so far
+            its = int(emitted_h.max())
+            pos0 = np.asarray([int(self.slot_pos[i]) for i in padded])
+            last = pos0[:, None] + np.minimum(np.arange(its)[None],
+                                              emitted_h[:, None])
+            self._count_attention(last, btab.shape[1], 1, its)
         shape = self._count_step(n_real, len(padded), K, 0,
                                  int(emitted_h[:n_real].sum()),
                                  len(padded) * K)

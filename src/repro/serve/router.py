@@ -63,6 +63,7 @@ _SUM_KEYS = (
     "drafted", "accepted", "host_syncs", "emitted_tokens",
     "prefix_hits", "prefix_hit_tokens", "prefill_tokens",
     "step_tokens", "step_slots", "step_host_ns",
+    "attn_kernel_steps", "attn_blocks_read", "attn_blocks_table",
     "queue_depth", "active_slots", "cow_copies", "shared_blocks",
 )
 # peaks take the max over replicas: the worst single-pool pressure seen

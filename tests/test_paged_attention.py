@@ -13,7 +13,13 @@ Property tests (hypothesis, or the deterministic shim on bare envs):
     block-boundary prompt lengths, and post-preemption re-prefill (all
     through the fused one-step-per-iteration scheduler);
   - every Sampler at temperature -> 0 equals the fused argmax
-    comparator (Theorem 1), including lowest-index tie-breaking.
+    comparator (Theorem 1), including lowest-index tie-breaking;
+  - multi-token steps as the engine builds them (decode rows beside
+    prefill chunks, narrow final chunks, tables padded past every
+    extent, extents off the copy group): kernel == ref twin, padded
+    query columns bit for bit their row's last real query;
+  - dispatch: on the chip ``use_pallas=False`` runs the compiled kernel
+    where it applies and the ref twin elsewhere; off it nothing changed.
 """
 import jax
 import jax.numpy as jnp
@@ -28,6 +34,7 @@ except ImportError:                     # bare env: deterministic shim
 
 from repro.configs import ARCHS, smoke_config
 from repro.kernels import ops, ref
+from repro.kernels import paged_attention as pa
 from repro.models import lm
 from repro.serve.engine import Request, ServeEngine
 from repro.serve.sampler import (
@@ -199,6 +206,133 @@ def test_paged_kernel_block_boundaries():
         p = np.asarray(ops.paged_attention(q, kp, vp, bt, jnp.int32(pos),
                                            use_pallas=True, interpret=True))
         np.testing.assert_allclose(p, r, rtol=2e-5, atol=2e-6)
+
+
+def _window_case(rng, rows, t, bs, nb=None, g=2, hkv=2, hd=16):
+    """A multi-token step as the engine builds it: ``rows`` gives each
+    row's (largest position, real width); a row's padding repeats its
+    last (query, position), and its table, padded with its own first
+    block, is ``nb`` wide (the pow-2 cover of the widest row if None)."""
+    need = [last // bs + 1 for last, _ in rows]
+    nb = nb or 1 << (max(need) - 1).bit_length()
+    nblocks = sum(need) + 3
+    perm = rng.permutation(nblocks)
+    tables, pos, q = [], [], rng.normal(size=(len(rows), t, g * hkv, hd))
+    for r, ((last, w), n) in enumerate(zip(rows, need)):
+        own = perm[sum(need[:r]):sum(need[:r]) + n]
+        tables.append(np.concatenate([own, np.repeat(own[:1], nb - n)]))
+        pos.append(last - w + 1 + np.minimum(np.arange(t), w - 1))
+        q[r, w:] = q[r, w - 1]
+    kp = rng.normal(size=(nblocks, bs, hkv, hd))
+    vp = rng.normal(size=(nblocks, bs, hkv, hd))
+    return (jnp.asarray(q, jnp.float32), jnp.asarray(kp, jnp.float32),
+            jnp.asarray(vp, jnp.float32), jnp.asarray(np.stack(tables),
+                                                      jnp.int32),
+            jnp.asarray(np.stack(pos), jnp.int32))
+
+
+# case -> (rows as (largest position, real width), T, block size, table
+# width (None: pow-2 cover), mode, window).  Copy groups are 16 pages.
+_WINDOW_CASES = {
+    # decode rows riding a step widened by 64-wide prefill chunks
+    "decode_and_64_chunks": ([(37, 1), (100, 64), (5, 1), (130, 64),
+                              (16, 1)], 64, 8, None, "exact", None),
+    # final chunks narrower than the step's width
+    "narrow_final_chunks": ([(40, 16), (23, 5), (9, 1), (63, 11), (2, 3)],
+                            16, 2, None, "exact", None),
+    # a table padded far past every row's extent
+    "table_past_every_extent": ([(3, 1), (17, 4), (30, 2)], 4, 8, 64,
+                                "exact", None),
+    # extents at block edges that are and are not a multiple of a group:
+    # 16, 17, 32 and 33 pages, and one page
+    "block_and_group_edges": ([(31, 1), (32, 2), (63, 1), (64, 2), (65, 2),
+                               (0, 1), (3, 2)], 2, 2, None, "exact", None),
+    "window": ([(37, 1), (40, 8), (5, 1), (19, 8)], 8, 2, None, "exact", 5),
+    "maxonly": ([(37, 1), (40, 8), (5, 1), (19, 8)], 8, 2, None, "maxonly",
+                None),
+    "pseudo": ([(37, 1), (40, 8), (5, 1), (19, 8)], 8, 2, None, "pseudo",
+               None),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_WINDOW_CASES))
+def test_paged_kernel_windows_match_ref(case):
+    """The kernel (interpret mode) equals the ref twin on the steps the
+    engine builds — decode rows computed once for their one real query,
+    chunk rows over their whole window, live pages only, in copy groups
+    that need not divide a row's extent — and every padded query column
+    is its row's last real query, bit for bit."""
+    rows, t, bs, nb, mode, window = _WINDOW_CASES[case]
+    rng = np.random.default_rng(sorted(_WINDOW_CASES).index(case))
+    q, kp, vp, bt, pos = _window_case(rng, rows, t, bs, nb)
+    r = np.asarray(ref.paged_attention(q, kp, vp, bt, pos,
+                                       attn_approx=mode, window=window))
+    k = np.asarray(pa.paged_attention(q, kp, vp, bt, pos, interpret=True,
+                                      attn_approx=mode, window=window))
+    np.testing.assert_allclose(k, r, rtol=5e-5, atol=5e-5)
+    for row, (_, w) in enumerate(rows):
+        for col in range(w, t):
+            np.testing.assert_array_equal(k[row, col], k[row, w - 1])
+
+
+# ---------------------------------------------------------------------------
+# Dispatch: which twin ops.paged_attention runs
+# ---------------------------------------------------------------------------
+@pytest.fixture
+def twins(monkeypatch):
+    """Both twins replaced by spies that record which one the dispatch
+    picked, and how (nothing runs or compiles)."""
+    calls = []
+    monkeypatch.setattr(pa, "paged_attention",
+                        lambda *a, **kw: calls.append(
+                            ("kernel", kw["interpret"])))
+    monkeypatch.setattr(ref, "paged_attention",
+                        lambda *a, **kw: calls.append(("ref", None)))
+    return calls
+
+
+def _dispatch(t, **kw):
+    q = jnp.zeros((2, 4, 8) if t == 1 else (2, t, 4, 8), jnp.float32)
+    pool = jnp.zeros((4, 4, 2, 8), jnp.float32)
+    pos = jnp.zeros((2,) if t == 1 else (2, t), jnp.int32)
+    ops.paged_attention(q, pool, pool, jnp.zeros((2, 2), jnp.int32), pos,
+                        **kw)
+
+
+@pytest.mark.parametrize("mode,t,split,want", [
+    ("exact", 1, False, ("kernel", False)),
+    ("exact", 64, False, ("kernel", False)),
+    ("pseudo", 128, False, ("kernel", False)),
+    ("maxonly", 8, False, ("kernel", False)),
+    ("base2", 1, False, ("ref", None)),
+    ("pwl", 64, False, ("ref", None)),
+    ("exact", 256, False, ("ref", None)),
+    ("exact", 1, True, ("ref", None)),
+])
+def test_dispatch_on_the_chip(twins, monkeypatch, mode, t, split, want):
+    """With the backend read as the chip's, ``use_pallas=False`` runs the
+    compiled kernel for the modes it lowers at T <= 128 over a whole
+    pool, and the reference otherwise."""
+    from types import SimpleNamespace
+
+    from repro.parallel import env
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    mesh = SimpleNamespace(shape={"data": 1, "model": 2}) if split else None
+    with env.use_mesh(mesh):
+        _dispatch(t, attn_approx=mode)
+        assert pa.kernel_runs(t, attn_approx=mode) == (want[0] == "kernel")
+    assert twins == [want]
+
+
+@pytest.mark.parametrize("use_pallas,want", [(False, ("ref", None)),
+                                             (True, ("kernel", True))])
+def test_dispatch_off_the_chip(twins, use_pallas, want):
+    """On the CPU nothing changed: the reference by default, the kernel
+    in interpret mode when ``use_pallas`` asks for it."""
+    for t in (1, 64):
+        _dispatch(t, use_pallas=use_pallas)
+    assert twins == [want, want]
 
 
 # ---------------------------------------------------------------------------

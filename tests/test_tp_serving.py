@@ -89,13 +89,19 @@ def test_aggregate_engine_stats_merge_rules():
     a = {"emitted_tokens": 10, "decode_steps": 5, "drafted": 4,
          "accepted": 3, "host_syncs": 5, "peak_in_use": 7,
          "step_tokens": 40, "step_slots": 64, "live_peak": 3,
+         "attn_kernel_steps": 5, "attn_blocks_read": 20,
+         "attn_blocks_table": 80,
          "attn_approx": "exact", "attn_window": None}
     b = {"emitted_tokens": 6, "decode_steps": 3, "drafted": 0,
          "accepted": 0, "host_syncs": 3, "peak_in_use": 2,
-         "step_tokens": 9, "step_slots": 16, "live_peak": 5}
+         "step_tokens": 9, "step_slots": 16, "live_peak": 5,
+         "attn_kernel_steps": 3, "attn_blocks_read": 7,
+         "attn_blocks_table": 24}
     agg = aggregate_engine_stats([a, b], ttft_pools=[[10.0, 30.0], [20.0]])
     assert agg["emitted_tokens"] == 16          # counters sum
     assert agg["step_tokens"] == 49 and agg["step_slots"] == 80
+    assert (agg["attn_kernel_steps"], agg["attn_blocks_read"],
+            agg["attn_blocks_table"]) == (8, 27, 104)
     assert agg["peak_in_use"] == 7              # peaks max, never sum
     assert agg["live_peak"] == 5
     # ratios recomputed from summed terms — NOT averaged (replica b's
@@ -157,9 +163,10 @@ def test_router_least_loaded_and_order(setup):
     # the aggregate invariant, through the real payload
     p = router.stats_payload()
     for k in ("emitted_tokens", "decode_steps", "completed", "step_tokens",
-              "step_slots"):
+              "step_slots", "attn_blocks_read", "attn_blocks_table"):
         assert p["engine"][k] == sum(r["engine"][k] for r in p["replicas"])
     assert p["engine"]["step_tokens"] > 0
+    assert p["engine"]["attn_blocks_table"] > 0
     assert p["engine"]["live_peak"] == \
         max(r["engine"]["live_peak"] for r in p["replicas"])
     assert p["engine"]["emitted_tokens"] == 16

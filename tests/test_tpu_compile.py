@@ -8,6 +8,10 @@ cannot lower, slices off the tiling, more scoped VMEM than a kernel may
 use.  ``tpu_custom_call`` in the compiled text shows the kernel was
 compiled for the chip, not interpreted.
 
+The serving step itself is compiled at the chat cell's widest shape,
+with ``jax.default_backend`` read as the chip's so the dispatch picks
+what it picks on the chip: the in-place paged kernel.
+
 The topology is described inside a module fixture, never at import: only
 one process may load the TPU library, and the worker that runs this
 file keeps it until it exits.  The persistent compilation cache is off
@@ -24,6 +28,7 @@ from repro.kernels import flash_attention as fa
 from repro.kernels import fused_argmax_head as fah
 from repro.kernels import fused_topk_head as ftk
 from repro.kernels import paged_attention as pa
+from repro.models import lm
 
 CFG = get_config("qwen3-0.6b")
 D, V = CFG.d_model, CFG.vocab_size
@@ -36,6 +41,11 @@ TABLE = 128               # block-table columns: 2048 tokens / 16
 # windows (spec_k 4) pad to
 PAGED_WIDTHS = (1, 2, 4, 8, 16, 32, 64)
 BF16, I32 = jnp.bfloat16, jnp.int32
+# the chat cell's widest step: 64 rows, tables of 256 blocks over a pool
+# of 3,072 16-token blocks; one layer's dense K/V view of such a step
+# (64 rows x 4,096 tokens x 8 heads x 128 x bf16, K and V) is 1 GiB
+STEP_ROWS, STEP_TABLE, STEP_POOL = 64, 256, 3072
+DENSE_VIEW_BYTES = 2 * STEP_ROWS * STEP_TABLE * BLOCK * HKV * HD * 2
 
 
 @pytest.fixture(scope="module")
@@ -98,6 +108,40 @@ def test_paged_attention_compiles(one_chip, t):
                                                   interpret=False),
         one_chip, (q, BF16), pool, pool, ((SLOTS, TABLE), I32), (pos, I32))
     assert "tpu_custom_call" in txt
+
+
+@pytest.mark.parametrize("t", [1, 64])
+def test_decode_step_reads_the_pool_in_place(one_chip, t, monkeypatch):
+    """The serving step at the chat cell's widest shape, compiled for a
+    v5e through the dispatch (the backend read as the chip's): the paged
+    kernel is in the program and no dense K/V view is — its temporaries
+    stay under one layer's view (the reference's gather needs ~2.2 GiB)."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+    def on_chip(x, dtype=None):
+        return jax.ShapeDtypeStruct(x.shape, dtype or x.dtype,
+                                    sharding=one_chip)
+
+    params = jax.tree.map(
+        lambda x: on_chip(x, BF16 if jnp.issubdtype(x.dtype, jnp.floating)
+                          else None),
+        jax.eval_shape(lambda: lm.init_params(CFG, jax.random.PRNGKey(0))))
+    cache = jax.tree.map(
+        lambda l: jax.ShapeDtypeStruct(
+            (l.shape[0], STEP_POOL, BLOCK) + l.shape[3:], l.dtype,
+            sharding=one_chip),
+        jax.eval_shape(lambda p: lm.init_cache(p, CFG, 1, 4096), params))
+    toks = jax.ShapeDtypeStruct((STEP_ROWS, t), I32, sharding=one_chip)
+    pos = jax.ShapeDtypeStruct((STEP_ROWS,) if t == 1 else (STEP_ROWS, t),
+                               I32, sharding=one_chip)
+    btab = jax.ShapeDtypeStruct((STEP_ROWS, STEP_TABLE), I32,
+                                sharding=one_chip)
+    compiled = jax.jit(
+        lambda p, x, c, q, b: lm.decode_step(p, CFG, x, c, q,
+                                             block_tables=b)).lower(
+        params, toks, cache, pos, btab).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < DENSE_VIEW_BYTES
 
 
 def test_flash_attention_prefill_compiles(one_chip):

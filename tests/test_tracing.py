@@ -11,6 +11,8 @@
     nothing else: the program and the tokens are those of a trace with
     every scope taken out.
   - COUNTERS: ``step_tokens`` / ``step_slots`` against a hand count;
+    ``attn_kernel_steps`` / ``attn_blocks_read`` / ``attn_blocks_table``
+    against a hand count under the reference and under the kernel;
     ``live_blocks`` against a brute-force count of the slot tables
     through alloc, adopt, release, preempt, rewind and evict;
     ``reset_live_peak``.
@@ -223,6 +225,56 @@ def test_step_tokens_on_the_multistep_path_count_emissions(setup):
     assert emitted == 3 * 4
     assert eng.stats["step_tokens"] - before["step_tokens"] == emitted
     assert eng.stats["step_slots"] - before["step_slots"] == 4 * 4
+
+
+def _attn(eng) -> tuple:
+    return tuple(eng.stats[k] for k in ("attn_kernel_steps",
+                                        "attn_blocks_read",
+                                        "attn_blocks_table"))
+
+
+@pytest.mark.parametrize("use_pallas,want", [
+    # the reference gathers every padded row's whole table
+    (False, (0, 15, 15)),
+    # the kernel reads each padded row's live blocks
+    (True, (4, 10, 15)),
+])
+def test_attention_counters_hand_count(setup, use_pallas, want):
+    import dataclasses
+
+    cfg, params = setup
+    eng = _engine(dataclasses.replace(cfg, use_pallas=use_pallas), params)
+    eng.submit(Request(0, _prompt(cfg, 9, 6),
+                       params=SamplingParams(max_new_tokens=8)))
+    for _ in range(3):
+        eng.step()
+    # chunks 0-3, 4-7 and the final 8: one row at last positions 3, 7
+    # and 8, tables 1, 2 and 4 wide (pow 2 of 1, 2, 3 live blocks)
+    eng.submit(Request(1, _prompt(cfg, 2, 7),
+                       params=SamplingParams(max_new_tokens=8)))
+    eng.step()
+    # row 0 decodes at 9 (3 live blocks), row 1's 2-token chunk ends at
+    # 1 (1 block): 2 rows x a 4-wide table
+    # table 1 + 2 + 4 + 8 = 15; live 1 + 2 + 3 + (3 + 1) = 10
+    assert _attn(eng) == want
+    assert eng.snapshot()["attn_blocks_table"] == 15
+
+
+def test_attention_counters_on_the_multistep_path(setup):
+    import dataclasses
+
+    cfg, params = setup
+    eng = _engine(dataclasses.replace(cfg, use_pallas=True), params,
+                  host_stride=4)
+    for rid in range(3):
+        eng.submit(Request(rid, _prompt(cfg, 4, 30 + rid),
+                           params=SamplingParams(max_new_tokens=6)))
+    eng.step()                          # the three final chunks
+    before = _attn(eng)
+    eng.step()                          # one multi-step dispatch
+    # 4 loop iterations over 4 padded rows at positions 4-7 (the padding
+    # row stays at 4), a 2-wide table, both blocks live for every row
+    assert tuple(a - b for a, b in zip(_attn(eng), before)) == (4, 32, 32)
 
 
 # ---------------------------------------------------------------------------
